@@ -47,15 +47,15 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
     v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    live = valid_ref[0] > 0                             # (bk,)
-    s = jnp.where(live[None, :], s, NEG_INF)
+    live = valid_ref[0] > 0                             # (1, bk)
+    s = jnp.where(live, s, NEG_INF)
 
     m_prev = m_scr[...]                                 # (group,)
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
     # mask the probabilities too: an all-dead block would otherwise
     # contribute exp(NEG_INF - NEG_INF) = 1 per slot
-    p = jnp.exp(s - m_cur[:, None]) * live[None, :].astype(jnp.float32)
+    p = jnp.exp(s - m_cur[:, None]) * live.astype(jnp.float32)
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
     acc_scr[...] = acc_scr[...] * alpha[:, None] + p @ v
     m_scr[...] = m_cur
@@ -91,7 +91,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
         validp = jnp.pad(validp, ((0, 0), (0, L_pad - L)))
-    validp = jnp.broadcast_to(validp, (B, L_pad))
+    # (B, 1, L_pad): a (1, block_k) block of the two minor dims is a legal
+    # TPU tile whatever B is
+    validp = jnp.broadcast_to(validp, (B, L_pad))[:, None, :]
 
     # q: (B, KV, group, hd); k/v: (B, KV, L_pad, hd)
     qt = q.reshape(B, KV, group, hd)
@@ -106,7 +108,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, 1, group, hd), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, ik: (b, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, ik: (b, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, hd),
                                lambda b, h, ik: (b, h, 0, 0)),

@@ -13,8 +13,10 @@ dtypes asserting allclose between them.
 """
 from __future__ import annotations
 
+import functools
 import os
 
+import jax
 import jax.numpy as jnp
 
 from . import ref
@@ -34,20 +36,45 @@ def kernel_impl() -> str:
 CHUNK_THRESHOLD = int(os.environ.get("REPRO_ATTN_CHUNK_THRESHOLD", 4096))
 
 
+def _ref_attention(q, k, v, causal, window):
+    S = q.shape[1]
+    if S >= CHUNK_THRESHOLD and S % min(1024, S) == 0 \
+            and q.shape[1] == k.shape[1]:
+        return ref.attention_chunked(q, k, v, causal=causal, window=window)
+    return ref.attention(q, k, v, causal=causal, window=window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, interpret):
+    from .flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=causal, window=window,
+                           interpret=interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, interpret):
+    return _flash(q, k, v, causal, window, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, window, interpret, res, g):
+    # the Pallas kernel has no backward pass: the gradient differentiates
+    # the XLA reference, recomputed from the saved q, k, v
+    _, vjp = jax.vjp(
+        lambda q, k, v: _ref_attention(q, k, v, causal, window), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, window: int = 0,
               impl: str | None = None) -> jnp.ndarray:
+    """Differentiable on every impl: the Pallas forward pairs with the
+    reference's backward."""
     impl = impl or kernel_impl()
     if impl == "ref":
-        S = q.shape[1]
-        if S >= CHUNK_THRESHOLD and S % min(1024, S) == 0 \
-                and q.shape[1] == k.shape[1]:
-            return ref.attention_chunked(q, k, v, causal=causal,
-                                         window=window)
-        return ref.attention(q, k, v, causal=causal, window=window)
-    from .flash_attention import flash_attention
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           interpret=(impl == "interpret"))
+        return _ref_attention(q, k, v, causal, window)
+    return _flash(q, k, v, causal, window, impl == "interpret")
 
 
 def attention_decode(q, k, v, valid, impl: str | None = None):
@@ -59,7 +86,8 @@ def attention_decode(q, k, v, valid, impl: str | None = None):
 
 
 def ring_gather(hist, idx, impl: str | None = None):
-    """Gather one stacked version: hist[(size, N)], idx scalar -> (N,)."""
+    """Gather one stacked version: hist (size, R, 128), idx scalar ->
+    hist[idx] (R, 128) — the packed ParameterDB ring row."""
     impl = impl or kernel_impl()
     if impl == "ref":
         return ref.ring_gather(hist, idx)

@@ -5,12 +5,14 @@ a pool of fixed-size pages shared by all sequence slots; a per-sequence
 page table maps logical cache pages to physical pool pages
 (vLLM-style paged attention, restricted to gather-before-attend).
 
-Materializing the logical (B, L, KV, hd) view is then a row-gather of
-``B * pages_per_seq`` pool rows.  Like kernels/ring_gather.py, the page
+Materializing the logical (B, L, KV, hd) view is then a gather of
+``B * pages_per_seq`` whole pages.  Like kernels/ring_gather.py, the page
 ids arrive through scalar prefetch (``PrefetchScalarGridSpec``) so the
 BlockSpec index map itself selects the physical page: the gather is pure
-DMA over lane-aligned tiles, one grid step per (page, tile) — no
-compute, no scatter, regardless of how fragmented the page table is.
+DMA, one grid step per page — no compute, no scatter, regardless of how
+fragmented the page table is.  A block is one whole page in the pool's
+own layout, so its two minor dims are the array's and the TPU tiling
+accepts it for any page size and head shape.
 """
 from __future__ import annotations
 
@@ -31,35 +33,34 @@ def _gather_kernel(pt_ref, pool_ref, out_ref):
     out_ref[...] = pool_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def page_gather(pool: jnp.ndarray, page_table: jnp.ndarray,
-                block: int = 1024, interpret: bool = False) -> jnp.ndarray:
-    """pool: (P, page, ...); page_table: (B, n_pp) int32 in [0, P).
+                interpret: bool = False) -> jnp.ndarray:
+    """pool: (P, page, *tail) with at least one tail dim; page_table:
+    (B, n_pp) int32 in [0, P).
 
-    Returns the logical view (B, n_pp * page, ...) in pool dtype, i.e.
+    Returns the logical view (B, n_pp * page, *tail) in pool dtype, i.e.
     ``pool[page_table]`` with the page axis folded into the cache axis.
     """
-    P, page = pool.shape[0], pool.shape[1]
-    tail = pool.shape[2:]
+    page, tail = pool.shape[1], pool.shape[2:]
     B, n_pp = page_table.shape
-    row = page * math.prod(tail)
-    rows = pool.reshape(P, row)
     idx = page_table.reshape(-1).astype(jnp.int32)        # (B * n_pp,)
-    block = min(block, row)
+    block = (1, page) + tail
+    zeros = (0,) * (len(tail) + 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B * n_pp, pl.cdiv(row, block)),
-        in_specs=[pl.BlockSpec((1, block),
-                               lambda i, j, pt_ref: (pt_ref[i], j))],
-        out_specs=pl.BlockSpec((1, block), lambda i, j, pt_ref: (i, j)),
+        grid=(B * n_pp,),
+        in_specs=[pl.BlockSpec(block, lambda i, pt_ref: (pt_ref[i],) + zeros)],
+        out_specs=pl.BlockSpec(block, lambda i, pt_ref: (i,) + zeros),
     )
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * n_pp, row), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * n_pp,) + pool.shape[1:],
+                                       pool.dtype),
         interpret=interpret,
-    )(idx, rows)
+    )(idx, pool)
     return out.reshape((B, n_pp * page) + tail)
 
 
@@ -79,11 +80,11 @@ def _prefill_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    kpos = kpos_ref[0]                                  # (bk,)
-    qp = qpos_ref[0]                                    # (R,)
-    live = (kpos[None, :] >= 0) & (kpos[None, :] <= qp[:, None])
+    kpos = kpos_ref[0]                                  # (1, bk)
+    qp = qpos_ref[0]                                    # (R, 1)
+    live = (kpos >= 0) & (kpos <= qp)
     if window > 0:
-        live &= kpos[None, :] > qp[:, None] - window
+        live &= kpos > qp - window
     s = jnp.where(live, s, NEG_INF)
 
     m_prev = m_scr[...]                                 # (R,)
@@ -152,6 +153,10 @@ def prefill_page_attention(q: jnp.ndarray, k_ctx: jnp.ndarray,
                            constant_values=-1)
     kt = k.transpose(0, 2, 1, 3)                        # (B, KV, T_pad, hd)
     vt = v.transpose(0, 2, 1, 3)
+    # key positions as rows and query positions as columns, so each block's
+    # two minor dims are legal TPU tiles and the mask needs no transpose
+    kpos = kpos[:, None, :]                             # (B, 1, T_pad)
+    qpos_row = qpos_row[:, :, None]                     # (B, R_pad, 1)
 
     grid = (B, KV, T_pad // block_k)
     out = pl.pallas_call(
@@ -161,8 +166,8 @@ def prefill_page_attention(q: jnp.ndarray, k_ctx: jnp.ndarray,
             pl.BlockSpec((1, 1, R_pad, hd), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, ik: (b, ik)),
-            pl.BlockSpec((1, R_pad), lambda b, h, ik: (b, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, ik: (b, 0, ik)),
+            pl.BlockSpec((1, R_pad, 1), lambda b, h, ik: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, R_pad, hd),
                                lambda b, h, ik: (b, h, 0, 0)),

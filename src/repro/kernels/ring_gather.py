@@ -7,15 +7,16 @@ but done leaf-by-leaf (the historical path) it lowers to one
 dynamic-slice DMA per pytree leaf, dozens per step for the zoo models.
 
 Here the row index arrives through scalar prefetch
-(``PrefetchScalarGridSpec``), so it is known before the kernel body runs
-and the BlockSpec index map itself selects the ring row: the whole
-gather is pure DMA over lane-aligned tiles of one *packed* (size, N)
-buffer — one kernel launch per parameter group, regardless of how many
-leaves the group holds.
+(``PrefetchScalarGridSpec``) and the kernel issues ONE HBM-to-HBM DMA of
+that row of one *packed* (size, R, 128) buffer — one kernel launch per
+parameter group, regardless of how many leaves the group holds.  Both
+operands stay in HBM (``pl.ANY``), so no VMEM block shape constrains the
+row: a ring of a few rows and ~10^8 lanes is as legal as a small one.
 
 The packed layout (leaves grouped by (delay, dtype), flattened and
-concatenated, N padded to the 128-lane tile) is built once at engine
-init by pdb/jax_backend.py; values round-trip bit-exactly.
+concatenated, padded to whole (8, 128) tiles and stored as (R, 128)
+rows) is built once at engine init by pdb/jax_backend.py; values
+round-trip bit-exactly.
 """
 from __future__ import annotations
 
@@ -27,34 +28,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gather_kernel(idx_ref, hist_ref, out_ref):
-    del idx_ref  # consumed by the BlockSpec index maps
-    out_ref[...] = hist_ref[...]
+def _gather_kernel(idx_ref, hist_ref, out_ref, sem):
+    copy = pltpu.make_async_copy(hist_ref.at[idx_ref[0]], out_ref, sem)
+    copy.start()
+    copy.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def ring_gather(hist: jnp.ndarray, idx: jnp.ndarray, block: int = 1024,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ring_gather(hist: jnp.ndarray, idx: jnp.ndarray,
                 interpret: bool = False) -> jnp.ndarray:
-    """hist: (size, N); idx: scalar int32 in [0, size) -> hist[idx] (N,).
-
-    N need not divide ``block``; Pallas clips the trailing tile.  For
-    peak DMA efficiency pack N to a multiple of 128 lanes (the jax
-    backend's packer does).
-    """
-    size, N = hist.shape
-    block = min(block, N)
+    """hist: (size, R, C); idx: scalar int32 in [0, size) -> hist[idx]
+    (R, C).  The ring axis must lie outside the two minor dims the TPU
+    tiles in HBM, or the row would be a slice across tiles."""
     idx = jnp.asarray(idx, jnp.int32).reshape(1)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(pl.cdiv(N, block),),
-        in_specs=[pl.BlockSpec((1, block), lambda i, idx_ref: (idx_ref[0], i))],
-        out_specs=pl.BlockSpec((1, block), lambda i, idx_ref: (0, i)),
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, N), hist.dtype),
+        out_shape=jax.ShapeDtypeStruct(hist.shape[1:], hist.dtype),
         interpret=interpret,
     )(idx, hist)
-    return out[0]

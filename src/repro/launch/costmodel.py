@@ -33,12 +33,9 @@ SDS = jax.ShapeDtypeStruct
 
 
 def cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: newer versions
-    return the per-device dict directly, older ones a one-element list."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost if cost is not None else {}
+    """``compiled.cost_analysis()``: the per-device dict, or {} when the
+    backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _slot_specs_for_group(cfg: ModelConfig, gi: int):

@@ -36,9 +36,10 @@ from ..optim import OptConfig, make_optimizer
 from .mesh import (DCI_FACTOR, HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                    make_production_mesh)
 from .shapes import SHAPES, applicable_shapes, decode_cache_specs, input_specs
-from .sharding import activation_rules, batch_shardings, \
-    opt_state_shardings, replicated, tree_shardings
-from .steps import make_decode_step, make_prefill_step, make_train_step
+from .sharding import activation_rules, batch_shardings, make_act_specs, \
+    replicated, tree_shardings
+from .steps import make_decode_step, make_prefill_step, \
+    make_sharded_train_step
 
 # In post-optimization (scheduled) HLO the operands are bare %names, so we
 # read each collective's RESULT type(s) from the LHS instead:
@@ -85,55 +86,26 @@ def _tree_flat_shardings(tree):
     return jax.tree.leaves(tree, is_leaf=lambda x: hasattr(x, "spec"))
 
 
-def make_act_specs(mesh, sp: bool = False) -> dict:
-    """Activation sharding constraints: block I/O sharded over the dp axes;
-    logits (and the CE one-hot) additionally vocab-sharded over `model` —
-    without this the softmax/one-hot temporaries replicate the vocab dim.
-
-    sp=True additionally shards the SEQUENCE dim of block I/O over `model`
-    (Megatron-style sequence parallelism): norms/residuals/embeddings run
-    seq-sharded and GSPMD inserts all-gathers only where attention needs the
-    full sequence — the fix for archs whose head count cannot shard over the
-    model axis (smollm: 15 heads on a 16-way axis)."""
-    from jax.sharding import NamedSharding, PartitionSpec as PS
-    if os.environ.get("REPRO_DP_OVER_MODEL") == "1":
-        dp = (("pod", "data", "model") if "pod" in mesh.shape
-              else ("data", "model"))
-        return {"act": NamedSharding(mesh, PS(dp, None, None)),
-                "logits": NamedSharding(mesh, PS(dp, None, None))}
-    dp = ("pod", "data") if "pod" in mesh.shape else ("data",)
-    dp = dp if len(dp) > 1 else dp[0]
-    seq_ax = "model" if sp else None
-    # logits keep vocab (not seq) on `model` — one axis, one dim
-    return {"act": NamedSharding(mesh, PS(dp, seq_ax, None)),
-            "logits": NamedSharding(mesh, PS(dp, None, "model"))}
-
-
 def build_cell(arch: str, shape_name: str, mesh, sync: SyncConfig):
     """Returns (jitted_fn, example_args (abstract), out_shardings_note)."""
     cfg = get_config(arch)
-    specs = model_specs(cfg)
-    params_abs = paramlib.abstract_tree(specs, cfg.param_dtype)
-    axes = paramlib.axes_tree(specs)
-    p_shard = tree_shardings(axes, params_abs, mesh, sync.param_rules)
-    act_specs = make_act_specs(mesh, sp=os.environ.get("REPRO_SP") == "1")
-
     cell = SHAPES[shape_name]
     batch_abs, batch_axes = input_specs(cfg, shape_name)
-    b_shard = batch_shardings(batch_axes, batch_abs, mesh)
 
     if cell.kind == "train":
         opt = make_optimizer(OptConfig(name="adamw",
                                        compression=sync.compression))
-        step = make_train_step(cfg, opt, sync, act_specs=act_specs)
-        opt_abs = jax.eval_shape(opt.init, params_abs)
-        o_shard = opt_state_shardings(p_shard, opt_abs, mesh)
-        fn = jax.jit(step,
-                     in_shardings=(p_shard, o_shard, b_shard),
-                     out_shardings=(p_shard, o_shard, None),
-                     donate_argnums=(0, 1))
-        args = (params_abs, opt_abs, batch_abs)
-    elif cell.kind == "prefill":
+        fn, params_abs, opt_abs, _ = make_sharded_train_step(
+            cfg, opt, sync, mesh, batch_abs, batch_axes)
+        return cfg, fn, (params_abs, opt_abs, batch_abs)
+
+    specs = model_specs(cfg)
+    params_abs = paramlib.abstract_tree(specs, cfg.param_dtype)
+    p_shard = tree_shardings(paramlib.axes_tree(specs), params_abs, mesh,
+                             sync.param_rules)
+    act_specs = make_act_specs(mesh, sp=os.environ.get("REPRO_SP") == "1")
+    b_shard = batch_shardings(batch_axes, batch_abs, mesh)
+    if cell.kind == "prefill":
         step = make_prefill_step(cfg, cache_len=cell.seq_len,
                                  remat=sync.remat, act_specs=act_specs)
         cache_abs, cache_ax = decode_cache_specs(cfg, shape_name)

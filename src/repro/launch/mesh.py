@@ -11,17 +11,27 @@ jax initialization (see dryrun.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # the sharding engine (launch/sharding.py) places arrays and constrains
+    # activations for GSPMD to propagate: Auto axes, not make_mesh's
+    # default Explicit ones, under which e.g. the embedding gather of a
+    # data-sharded table by data-sharded tokens is a type error
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for subprocess tests (8 forced host devices)."""
-    return jax.make_mesh(shape, axes)
+    """Small mesh over the first devices: forced host devices in tests,
+    the real chips in ``chip_smoke.py --chips 4``."""
+    return _auto_mesh(shape, axes)
 
 
 # TPU v5e hardware constants used by the roofline analysis

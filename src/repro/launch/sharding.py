@@ -100,3 +100,26 @@ def opt_state_shardings(param_shardings, opt_state_abstract, mesh: Mesh):
             return sub
         return replicated(mesh)
     return jax.tree_util.tree_map_with_path(pick, opt_state_abstract)
+
+
+def make_act_specs(mesh, sp: bool = False) -> dict:
+    """Activation sharding constraints: block I/O sharded over the dp axes;
+    logits (and the CE one-hot) additionally vocab-sharded over `model` —
+    without this the softmax/one-hot temporaries replicate the vocab dim.
+
+    sp=True additionally shards the SEQUENCE dim of block I/O over `model`
+    (Megatron-style sequence parallelism): norms/residuals/embeddings run
+    seq-sharded and GSPMD inserts all-gathers only where attention needs the
+    full sequence — the fix for archs whose head count cannot shard over the
+    model axis (smollm: 15 heads on a 16-way axis)."""
+    if os.environ.get("REPRO_DP_OVER_MODEL") == "1":
+        dp = (("pod", "data", "model") if "pod" in mesh.shape
+              else ("data", "model"))
+        return {"act": NamedSharding(mesh, PS(dp, None, None)),
+                "logits": NamedSharding(mesh, PS(dp, None, None))}
+    dp = ("pod", "data") if "pod" in mesh.shape else ("data",)
+    dp = dp if len(dp) > 1 else dp[0]
+    seq_ax = "model" if sp else None
+    # logits keep vocab (not seq) on `model` — one axis, one dim
+    return {"act": NamedSharding(mesh, PS(dp, seq_ax, None)),
+            "logits": NamedSharding(mesh, PS(dp, None, "model"))}

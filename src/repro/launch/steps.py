@@ -9,6 +9,7 @@ step code.
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Callable
 
 import jax
@@ -16,10 +17,13 @@ import jax.numpy as jnp
 
 from ..pdb.jax_backend import TrainEngine, make_engine
 from ..core.sync_jax import SyncConfig
+from ..models import paramlib
 from ..models.config import ModelConfig
 from ..models.transformer import decode_step as model_decode
-from ..models.transformer import lm_loss, prefill
+from ..models.transformer import lm_loss, model_specs, prefill
 from ..optim.optimizers import Optimizer
+from .sharding import (batch_shardings, make_act_specs, opt_state_shardings,
+                       tree_shardings)
 
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, sync: SyncConfig,
@@ -33,6 +37,31 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, sync: SyncConfig,
         metrics = dict(metrics)
         return new_params, new_opt, metrics
     return train_step
+
+
+def make_sharded_train_step(cfg: ModelConfig, opt: Optimizer,
+                            sync: SyncConfig, mesh, batch_abs: dict,
+                            batch_axes: dict):
+    """The SPMD train step on ``mesh``: parameters laid out by the sync
+    mode's rules (``datacentric`` shards them over ``data``, ``bsp``
+    replicates them), optimizer state mirroring them, batch over the data
+    axes; parameters and optimizer state donated.
+
+    Returns (jitted step, abstract params, abstract optimizer state,
+    (param, optimizer-state, batch) shardings)."""
+    specs = model_specs(cfg)
+    params_abs = paramlib.abstract_tree(specs, cfg.param_dtype)
+    p_shard = tree_shardings(paramlib.axes_tree(specs), params_abs, mesh,
+                             sync.param_rules)
+    act_specs = make_act_specs(mesh, sp=os.environ.get("REPRO_SP") == "1")
+    opt_abs = jax.eval_shape(opt.init, params_abs)
+    o_shard = opt_state_shardings(p_shard, opt_abs, mesh)
+    b_shard = batch_shardings(batch_axes, batch_abs, mesh)
+    fn = jax.jit(make_train_step(cfg, opt, sync, act_specs=act_specs),
+                 in_shardings=(p_shard, o_shard, b_shard),
+                 out_shardings=(p_shard, o_shard, None),
+                 donate_argnums=(0, 1))
+    return fn, params_abs, opt_abs, (p_shard, o_shard, b_shard)
 
 
 def make_lm_grad_fn(cfg: ModelConfig, sync: SyncConfig) -> Callable:

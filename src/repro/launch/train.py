@@ -1,8 +1,13 @@
-"""Training driver: single-host CPU execution of the full stack.
+"""Training driver: the full stack in one process, on the default device.
 
-Runs the real training loop (any zoo arch at reduced scale, or the full
-config if you have the hardware) with:
-  * bsp vs datacentric vs ssp parameter layouts (sync mode),
+Runs the real training loop (any zoo arch: ``--smoke`` widths on a CPU,
+published widths on a TPU — ``chip_smoke.py`` runs SmolLM-360M on one
+v5e) with:
+  * the sync mode (bsp / datacentric / ssp).  It selects a sharded
+    parameter layout only where a mesh is built (launch/dryrun.py and
+    ``chip_smoke.py --chips 4``); this single-process driver builds none,
+    so here ``--mode`` changes no layout (it picks the server backend's
+    policy),
   * delta-staleness via the unified ParameterDB train engine
     (repro.pdb.jax_backend), with Op/staleness telemetry,
   * a multi-process sharded parameter-server backend (--backend server):
@@ -160,7 +165,8 @@ def run_server_backend(args) -> dict:
             "rpc_retries": retries}
 
 
-def main(argv=None) -> dict:
+def make_parser() -> argparse.ArgumentParser:
+    """The driver's command line (also used by ``chip_smoke.py``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -202,7 +208,11 @@ def main(argv=None) -> dict:
     ap.add_argument("--snapshot-dir", default="",
                     help="server backend: shard snapshot directory "
                          "(crash-restart survival)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> dict:
+    args = make_parser().parse_args(argv)
     apply_tuning()
 
     if args.backend == "server":

@@ -1,68 +1,59 @@
-"""XLA / platform tuning knobs, applied in one place (SNIPPETS.md §3).
+"""Compiler flags and the persistent compile cache, set up in one place.
 
-Benchmarks and the training driver call :func:`apply_tuning` first thing,
-so every number in BENCH_*.json reflects the same tuned baseline:
+Every entry point (the train and serve drivers, the benchmarks and
+``chip_smoke.py``) calls :func:`apply_tuning` before its first device
+computation, because XLA and libtpu read their flags when the backend
+initialises:
 
-  * async collectives + latency-hiding scheduler (GPU; the TPU scheduler
-    flag where supported) — overlaps the ParameterDB all-gathers /
-    reduce-scatters with compute, which is the whole point of the
-    data-centric sharded layout;
-  * ``--xla_force_host_platform_device_count=N`` — multi-device SPMD on a
-    CPU host (the dry-run / CI environment);
-  * optional f64 switch for numerics experiments.
+  * TPU compiler flags (the latency-hiding scheduler, which overlaps the
+    ParameterDB all-gathers / reduce-scatters with compute) are appended to
+    ``LIBTPU_INIT_ARGS``.  They never go into ``XLA_FLAGS``, whose parser
+    rejects TPU flags fatally, and what the variable already holds is kept;
+  * JAX's persistent compile cache: ``JAX_COMPILATION_CACHE_DIR`` when it
+    is set (JAX reads it itself), else, on a TPU, the fixed
+    ``<checkout>/.jax_cache``.  The path is part of the cache key, so it
+    must not move between runs.
 
-XLA reads these from the environment at backend init, so tuning must run
-before the first device computation; flags are appended idempotently and
-``REPRO_TUNE=0`` disables everything (untuned A/B baseline).
+Flags are appended idempotently; ``REPRO_TUNE=0`` disables the flags
+(untuned A/B baseline) but not the compile cache, which changes no
+program.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
-GPU_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true",
-    "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
 TPU_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true",
 )
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def apply_tuning(platform: str | None = None,
-                 host_device_count: int | None = None,
-                 enable_x64: bool = False) -> list[str]:
-    """Append tuning flags to XLA_FLAGS; returns the flags added.
+def setup_compile_cache() -> str | None:
+    """Point JAX's persistent compile cache at its one directory; returns
+    that directory (None: no cache, because no TPU backs this process)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.default_backend() != "tpu":
+        # host-compiled entries are not kept: they would land in the
+        # checkout and be loaded back on whatever machine it is copied to
+        return None
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
-    platform: "cpu" | "gpu" | "tpu" | None (autodetect from JAX_PLATFORMS,
-    default cpu).  Safe to call repeatedly — already-present flags are
-    skipped.  No-op when REPRO_TUNE=0.
-    """
-    if os.environ.get("REPRO_TUNE", "1") == "0":
-        return []
-    platform = platform or os.environ.get("JAX_PLATFORMS", "cpu").split(",")[0]
 
-    flags: list[str] = []
-    if platform == "gpu":
-        flags += GPU_FLAGS
-    elif platform == "tpu":
-        flags += TPU_FLAGS
-    if host_device_count is not None:
-        try:
-            n_cores = os.cpu_count() or 1
-        except Exception:  # pragma: no cover
-            n_cores = 1
-        n = min(int(host_device_count), max(n_cores, 1))
-        flags.append(f"--xla_force_host_platform_device_count={n}")
-
-    current = os.environ.get("XLA_FLAGS", "")
-    added = [f for f in flags
-             if f.split("=")[0] not in current]
-    if added:
-        os.environ["XLA_FLAGS"] = (current + " " + " ".join(added)).strip()
-    if enable_x64:
-        import jax
-        jax.config.update("jax_enable_x64", True)
+def apply_tuning() -> list[str]:
+    """Append the TPU flags to ``LIBTPU_INIT_ARGS``, then set up the
+    compile cache, which initialises the JAX backend; returns the flags
+    added.  Safe to call repeatedly — flags already present are skipped.
+    The flags are a no-op when REPRO_TUNE=0."""
+    added: list[str] = []
+    if os.environ.get("REPRO_TUNE", "1") != "0":
+        current = os.environ.get("LIBTPU_INIT_ARGS", "")
+        added = [f for f in TPU_FLAGS if f.split("=")[0] not in current]
+        if added:
+            os.environ["LIBTPU_INIT_ARGS"] = " ".join([current] + added).strip()
+    setup_compile_cache()
     return added

@@ -62,16 +62,18 @@ jax.tree_util.register_pytree_node(
 # ---------------------------------------------------------------------------
 # Packed ring layout (the Pallas fast path)
 #
-# Leaves are grouped by (admissible delay, dtype), flattened and concatenated
-# into one (size, N) buffer per group, N padded to the 128-lane tile.  A
-# stale read is then ONE row-gather per group (kernels/ring_gather.py, row
-# index via scalar prefetch) instead of one dynamic-slice DMA per leaf; a
-# write is one row update per group.  Packing round-trips bit-exactly, so
+# Leaves are grouped by (admissible delay, dtype), flattened, concatenated,
+# padded to whole (8, 128) tiles and stored as one (size, R, 128) buffer per
+# group: the ring axis then lies outside the two dims the TPU tiles, so a
+# version is a contiguous row.  A stale read is ONE row DMA per group
+# (kernels/ring_gather.py, row index via scalar prefetch) instead of one
+# dynamic-slice DMA per leaf; a write is one row update per group.  Packing round-trips bit-exactly, so
 # the delta=0 sequential-correctness guarantee is untouched (asserted in
 # tests/test_staleness_jax.py).
 # ---------------------------------------------------------------------------
 
 _LANE = 128
+_TILE = 8 * _LANE
 
 
 class _PackGroup(NamedTuple):
@@ -81,7 +83,7 @@ class _PackGroup(NamedTuple):
     idxs: tuple[int, ...]                 # flat-leaf indices in this group
     shapes: tuple[tuple[int, ...], ...]
     sizes: tuple[int, ...]
-    pad: int                              # zero-pad to the lane tile
+    pad: int                              # zero-pad to whole tiles
 
 
 def _pack_plan(params: PyTree, delta: int,
@@ -102,7 +104,7 @@ def _pack_plan(params: PyTree, delta: int,
             key=f"d{d}_{dtname}", delay=d, dtype=members[0][2],
             idxs=tuple(m[0] for m in members),
             shapes=tuple(m[1] for m in members),
-            sizes=sizes, pad=(-sum(sizes)) % _LANE))
+            sizes=sizes, pad=(-sum(sizes)) % _TILE))
     return plan, treedef, len(leaves)
 
 
@@ -113,7 +115,7 @@ def _pack_rows(plan: list[_PackGroup], leaves: list) -> dict:
         row = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         if g.pad:
             row = jnp.pad(row, (0, g.pad))
-        rows[g.key] = row
+        rows[g.key] = row.reshape(-1, _LANE)
     return rows
 
 
@@ -121,7 +123,7 @@ def _unpack_rows(plan: list[_PackGroup], rows: dict, treedef: Any,
                  n_leaves: int) -> PyTree:
     out: list = [None] * n_leaves
     for g in plan:
-        row, off = rows[g.key], 0
+        row, off = rows[g.key].reshape(-1), 0
         for i, shape, sz in zip(g.idxs, g.shapes, g.sizes):
             out[i] = jax.lax.slice_in_dim(row, off, off + sz).reshape(shape)
             off += sz
@@ -157,6 +159,13 @@ def init_delayed_state(params: PyTree, opt_init: Callable[[PyTree], PyTree],
                         step=jnp.zeros((), jnp.int32))
 
 
+def _keep_if(ok: jnp.ndarray, new: PyTree, old: PyTree) -> PyTree:
+    """``new`` where ``ok`` else ``old``, leaf by leaf.  The engine's steps
+    donate their input state, so a non-finite step must hand the prior
+    values back itself: its caller can no longer read the donated input."""
+    return jax.tree.map(lambda n, o: jnp.where(ok, n, o), new, old)
+
+
 def make_delayed_step(
     grad_fn: Callable[[PyTree, Any], tuple[jnp.ndarray, PyTree]],
     opt_update: Callable[[PyTree, PyTree, PyTree], tuple[PyTree, PyTree]],
@@ -164,7 +173,9 @@ def make_delayed_step(
     delay_for: Callable[[tuple], int] | None = None,
     packed: bool | None = None,
 ) -> Callable[[DelayedState, Any], tuple[DelayedState, dict]]:
-    """Build a jit-able delayed-gradient step.
+    """Build a jit-able delayed-gradient step.  A step whose loss is not
+    finite returns its input state unchanged (and does not advance ``ptr``
+    or ``step``), so the step may be jitted with its state donated.
 
     grad_fn(params, batch) -> (loss, grads)
     opt_update(grads, opt_state, params) -> (new_params, new_opt_state)
@@ -204,7 +215,12 @@ def make_delayed_step(
         stale_params = read_stale(state)
         loss, grads = grad_fn(stale_params, batch)
         new_params, new_opt = opt_update(grads, state.opt_state, state.params)
-        new_ptr = jnp.mod(state.ptr + 1, size)
+        ok = jnp.all(jnp.isfinite(loss))
+        new_params, new_opt = _keep_if(ok, (new_params, new_opt),
+                                       (state.params, state.opt_state))
+        # a skipped step rewrites theta[alpha] into its own slot (hist[ptr]
+        # already holds it bit-exactly), so the ring is untouched
+        new_ptr = jnp.where(ok, jnp.mod(state.ptr + 1, size), state.ptr)
         if use_packed:
             plan, _, _ = plan_cache["plan"]
             new_rows = _pack_rows(plan, jax.tree_util.tree_leaves(new_params))
@@ -219,7 +235,7 @@ def make_delayed_step(
                 state.hist, new_params)
         new_state = DelayedState(params=new_params, hist=new_hist,
                                  ptr=new_ptr, opt_state=new_opt,
-                                 step=state.step + 1)
+                                 step=state.step + ok.astype(jnp.int32))
         return new_state, {"loss": loss, "staleness": jnp.asarray(delta)}
 
     step.read_stale = read_stale
@@ -240,8 +256,9 @@ class TrainEngine:
     buffer's indexing (reads clipped below step 1 see the initial values),
     so warmup staleness ramps 0..delay exactly as on device.
 
-    Drivers that may *discard* a step's result (e.g. the fault layer
-    skipping a non-finite step) should call ``step_fn`` directly and then
+    A non-finite step returns its input state's values unchanged (the
+    input itself is donated).  Drivers that may *skip* such a step (e.g.
+    the fault layer) should call ``step_fn`` directly and then
     ``record_step()`` only for accepted steps, so the Op history matches
     the actual parameter evolution.
     """
@@ -284,6 +301,12 @@ def make_engine(params: PyTree,
     delta == 0 and no group delays: plain synchronous dict state
     {"params", "opt"} (checkpoint-compatible with the historical layout);
     otherwise: :class:`DelayedState` ring buffer with per-group delays.
+
+    ``step_fn`` donates its state argument, so the old and new states are
+    never both live on the device; a state passed to it must not be read
+    again.  The state ``init_state`` builds holds ``params`` itself (no
+    second copy on the device), so the first step consumes them: build
+    one state per engine.
     """
     delta = int(getattr(sync, "delta", 0))
     group_delays_cfg = tuple(getattr(sync, "group_delays", ()) or ())
@@ -298,14 +321,16 @@ def make_engine(params: PyTree,
     if delta == 0:
         def sync_step(state, batch):
             loss, grads = grad_fn(state["params"], batch)
-            new_params, new_opt = opt.update(grads, state["opt"],
-                                             state["params"])
+            new = opt.update(grads, state["opt"], state["params"])
+            ok = jnp.all(jnp.isfinite(loss))
+            new_params, new_opt = _keep_if(ok, new, (state["params"],
+                                                     state["opt"]))
             return ({"params": new_params, "opt": new_opt},
                     {"loss": loss, "staleness": jnp.zeros((), jnp.int32)})
 
         return TrainEngine(
             init_state=lambda: {"params": params, "opt": opt.init(params)},
-            step_fn=jax.jit(sync_step),
+            step_fn=jax.jit(sync_step, donate_argnums=0),
             telemetry=telemetry, delta=0, group_delays=delays)
 
     delay_for = sync.delay_for if group_delays_cfg else None
@@ -316,5 +341,5 @@ def make_engine(params: PyTree,
         init_state=lambda: init_delayed_state(params, opt.init, delta,
                                               packed=packed,
                                               delay_for=delay_for),
-        step_fn=jax.jit(raw),
+        step_fn=jax.jit(raw, donate_argnums=0),
         telemetry=telemetry, delta=delta, group_delays=delays)

@@ -133,8 +133,11 @@ def run_with_recovery(step_fn: Callable[[Any, Any], tuple[Any, dict]],
                       ) -> tuple[Any, dict, str]:
     """Execute one training step with recovery.  Returns
     (state, metrics, outcome) where outcome is 'ok' | 'retried' | 'skipped'.
-    On non-finite loss the state update is discarded (the prior state is
-    returned) — the safe default for poisoned batches.
+    On non-finite loss the step is reported as skipped — the safe default
+    for poisoned batches.  ``step_fn`` may donate ``state``, so the input is
+    never read after the call: a step that can be skipped must itself return
+    the prior values when its loss is non-finite (the train engine's steps
+    do, see :mod:`repro.pdb.jax_backend`).
 
     ``telemetry`` is an optional :class:`repro.pdb.telemetry.Telemetry`;
     retried and skipped steps are reported into it so one object summarizes
@@ -150,7 +153,7 @@ def run_with_recovery(step_fn: Callable[[Any, Any], tuple[Any, dict]],
                     log.warning("non-finite metrics at step %d; skipping", step)
                     if telemetry is not None:
                         telemetry.on_skip(step)
-                    return state, metrics, "skipped"
+                    return new_state, metrics, "skipped"
                 raise FloatingPointError(f"non-finite loss at step {step}")
             return new_state, metrics, ("ok" if attempts == 0 else "retried")
         except InjectedFailure:

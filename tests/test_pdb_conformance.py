@@ -248,6 +248,25 @@ def test_jax_engine_group_delays_telemetry():
     assert np.isfinite(float(m["loss"]))
 
 
+@pytest.mark.parametrize("delta", [0, 2])
+def test_jax_engine_nonfinite_step_keeps_state(delta):
+    """The engine's step donates its state, so a poisoned step must hand
+    the prior values back itself: parameters, ring pointer and step count
+    come out exactly as they went in."""
+    jax = pytest.importorskip("jax")
+    eng, batch = _toy_engine(delta=delta, record=False)
+    state = eng.init_state()
+    state, _ = eng.step(state, batch)
+    before = jax.tree.map(np.asarray, state)
+    poisoned = dict(batch, y=batch["y"].at[0].set(float("nan")))
+    state, m = eng.step_fn(state, poisoned)
+    assert not np.isfinite(float(m["loss"]))
+    jax.tree.map(np.testing.assert_array_equal, before,
+                 jax.tree.map(np.asarray, state))
+    state, m = eng.step_fn(state, batch)            # training resumes
+    assert np.isfinite(float(m["loss"]))
+
+
 # ---------------------------------------------------------------------------
 # The multi-process sharded backend (repro.pdb.server)
 # ---------------------------------------------------------------------------

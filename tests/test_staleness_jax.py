@@ -35,8 +35,12 @@ def test_delta0_bit_identical_to_sync(opt_name):
 
     @jax.jit
     def sync_step(p, s, b):
-        _, g = grad_fn(p, b)
-        return opt.update(g, s, p)
+        loss, g = grad_fn(p, b)
+        new = opt.update(g, s, p)
+        # the engine's guard (a non-finite step keeps the prior values);
+        # without it XLA fuses the update differently and rounds apart
+        ok = jnp.isfinite(loss)
+        return jax.tree.map(lambda n, o: jnp.where(ok, n, o), new, (p, s))
 
     p_sync, s_sync = params, opt.init(params)
     for _ in range(10):

@@ -1,5 +1,6 @@
 """Training-driver integration: fault injection, resume equivalence, sync
 modes, staleness, compression — the scale features at laptop scale."""
+import functools
 import subprocess
 import sys
 import os
@@ -68,17 +69,20 @@ def test_crash_and_resume_matches_uninterrupted(tmp_path):
 
 
 def test_run_with_recovery_skips_nonfinite():
-    calls = []
-
+    # a donating step that, like the train engine's, keeps the prior values
+    # itself when its loss is non-finite
+    @functools.partial(jax.jit, donate_argnums=0)
     def step(state, batch):
-        calls.append(1)
-        return state + 1, {"loss": jnp.asarray(float("nan"))}
+        loss = jnp.asarray(float("nan"))
+        return jnp.where(jnp.isfinite(loss), state + 1, state), {"loss": loss}
 
     state, metrics, outcome = run_with_recovery(
-        step, 0, None, 3, RetryPolicy(skip_nonfinite=True),
+        step, jnp.zeros(3), None, 3, RetryPolicy(skip_nonfinite=True),
         is_finite=lambda m: bool(jnp.isfinite(m["loss"])))
     assert outcome == "skipped"
-    assert state == 0                      # poisoned update discarded
+    # poisoned update discarded, and the state handed back is live (the
+    # donated input is not)
+    np.testing.assert_array_equal(np.asarray(state), np.zeros(3))
 
 
 def test_run_with_recovery_retries_transient():
