@@ -34,6 +34,7 @@ import argparse
 import os
 
 import jax
+import numpy as np
 
 from ..configs import get_config, get_smoke_config
 from ..models import paramlib
@@ -129,6 +130,14 @@ def main(argv=None) -> dict:
           f"p99 {report.ttft_p99*1e3:.0f}ms; "
           f"{report.prefill_chunks} prefill chunks, "
           f"prefix hit rate {report.prefix_hit_rate:.0%}")
+    done = report.requests
+    wait = [f.t_start - f.arrival for f in done]
+    prompt = [f.t_first - f.t_start for f in done]
+    tail = sum(f.tail_tokens for f in done)
+    print(f"ttft p99 split: waiting {np.percentile(wait, 99)*1e3:.0f}ms, "
+          f"prompt {np.percentile(prompt, 99)*1e3:.0f}ms; "
+          f"{tail / sum(len(r.prompt) for r in requests):.0%} of prompt "
+          f"tokens fed one a tick through decode")
     first = report.outputs[min(report.outputs)]
     print("first request:", list(first[:12]))
     return {"report": report, "tok_per_s": report.tokens_per_sec}

@@ -62,6 +62,24 @@ benchmarks/serve_bench.py.
 Two clocks: ``"wall"`` (arrivals in seconds, ``time.perf_counter``) for
 benchmarking, ``"steps"`` (arrivals in scheduler-tick indices, a virtual
 clock) for deterministic tests.
+
+Tracing: ``run`` marks its loop with ``jax.profiler`` annotations, which
+cost about a microsecond each when no profiler runs and land on the
+profiler's clock when one does (``jax.profiler.trace`` around ``run``).
+Each loop iteration that does work is one ``repro.serve.tick`` step
+(``step_num`` = the decode-step index), holding, in order,
+``repro.serve.admit`` (due arrivals queued, ``_try_admit``),
+``repro.serve.prefill`` (``_prefill_tick``), ``repro.serve.decode``
+(copy-on-write, slot arrays to the device, the decode dispatch),
+``repro.serve.readback`` (waiting for the sampled tokens) and
+``repro.serve.update`` (activation, the per-slot token loop, retirement).
+``repro.serve.wait`` is the sleep until the next arrival, outside any
+tick; ``step_hook`` runs after its tick has closed.  Each
+:class:`FinishedRequest` records when its prompt work started
+(``t_start``) and how many prompt tokens went through the decode step one
+a tick (``tail_tokens``), so time to first token splits into the wait
+before the prompt (``t_start - arrival``) and the prompt itself
+(``t_first - t_start``).
 """
 from __future__ import annotations
 
@@ -128,6 +146,8 @@ class FinishedRequest:
     t_first: float               # clock at first token (end of prefill)
     t_done: float                # clock at last token
     tokens: tuple[int, ...]
+    t_start: float = 0.0         # clock when its prompt work began
+    tail_tokens: int = 0         # prompt tokens fed one a tick via decode
 
     @property
     def latency(self) -> float:
@@ -155,12 +175,14 @@ class ServeReport:
     prefix_hit_rate: float       # fraction of prompt tokens adopted
     utilization: float           # mean fraction of live slots per decode step
     outputs: dict[int, tuple[int, ...]]
+    requests: list[FinishedRequest]  # finished records, in finishing order
+    t0: float                    # time.perf_counter() when the clock read 0
 
 
 class _Slot:
     __slots__ = ("req", "phase", "remaining", "tokens", "t_first",
-                 "fill_pos", "chunk_starts", "carry", "rows_dev",
-                 "rows_host", "shared", "nodes")
+                 "t_start", "tail_tokens", "fill_pos", "chunk_starts",
+                 "carry", "rows_dev", "rows_host", "shared", "nodes")
 
     def __init__(self, req: Request):
         self.req = req
@@ -168,6 +190,8 @@ class _Slot:
         self.remaining = 0
         self.tokens: list[int] = []
         self.t_first = 0.0
+        self.t_start = 0.0
+        self.tail_tokens = 0
         self.fill_pos = 0            # prompt positions < this are computed
         self.chunk_starts: list[int] = []  # pending prefill-chunk starts
         self.carry: Any = None       # recurrent state during chunked prefill
@@ -319,7 +343,7 @@ class ServeEngine:
         first = self._sample_one(logits, req.rid, 0)
         if req.gen_len <= 1:       # prompt-only request: done at prefill
             self._finished.append(FinishedRequest(
-                req.rid, req.arrival, now, now, (first,)))
+                req.rid, req.arrival, now, now, (first,), t_start=now))
             return
         b = self._free_slot()
         assert b is not None, "admission with no free slot"
@@ -335,6 +359,7 @@ class ServeEngine:
         s.remaining = req.gen_len - 1
         s.tokens = [first]
         s.t_first = now
+        s.t_start = now
         self.slots[b] = s
 
     def _admit_chunked(self, req: Request, now: float) -> None:
@@ -356,6 +381,7 @@ class ServeEngine:
         """Adopt any cached prefix, allocate the rest of the slot's
         pages, and plan the chunk schedule."""
         s = self.slots[b]
+        s.t_start = self._now()
         req = s.req
         prompt, S = req.prompt, len(req.prompt)
         page = self.scfg.page_size
@@ -422,26 +448,29 @@ class ServeEngine:
         s.carry = self._carry0
         s.shared = {L: set(range(a_pg)) for L in rows}
 
-    def _try_admit(self, queue: deque, now: float, n_left: int) -> bool:
-        admitted = False
-        chunked = self.scfg.prefill_chunk > 0
+    def _can_admit(self, n_queued: int, n_left: int) -> bool:
+        """Whether admission would take a request now, from a queue of
+        ``n_queued`` with ``n_left`` requests not yet admitted."""
         if self.scfg.continuous:
+            return n_queued > 0 and self._free_slot() is not None
+        # static baseline: an empty engine AND a full batch (or the tail of
+        # the workload)
+        return (all(s is None for s in self.slots)
+                and 0 < min(self.scfg.batch_size, n_left) <= n_queued)
+
+    def _try_admit(self, queue: deque, now: float, n_left: int) -> None:
+        if self.scfg.continuous:
+            chunked = self.scfg.prefill_chunk > 0
             while queue and self._free_slot() is not None:
                 req = queue.popleft()
                 if chunked:
                     self._admit_chunked(req, now)
                 else:
                     self._admit(req, now)
-                admitted = True
-        else:
-            # static baseline: wait for an empty engine AND a full batch
-            # (or the tail of the workload), then admit the whole wave
-            want = min(self.scfg.batch_size, n_left)
-            if all(s is None for s in self.slots) and len(queue) >= want:
-                for _ in range(want):
-                    self._admit(queue.popleft(), now)
-                    admitted = True
-        return admitted
+        elif self._can_admit(len(queue), n_left):
+            # static baseline: admit the whole wave
+            for _ in range(min(self.scfg.batch_size, n_left)):
+                self._admit(queue.popleft(), now)
 
     # -- chunked prefill / activation -------------------------------------
 
@@ -495,6 +524,7 @@ class ServeEngine:
                 self._retire(b, now)
         else:                          # remainder: teacher-forced decode
             s.phase = "tail"
+            s.tail_tokens = S - s.fill_pos
             self._tok[b, 0] = s.req.prompt[s.fill_pos]
             self._pos[b] = s.fill_pos
             self._ctr[b] = 0
@@ -544,7 +574,8 @@ class ServeEngine:
     def _retire(self, b: int, now: float) -> None:
         s = self.slots[b]
         self._finished.append(FinishedRequest(
-            s.req.rid, s.req.arrival, s.t_first, now, tuple(s.tokens)))
+            s.req.rid, s.req.arrival, s.t_first, now, tuple(s.tokens),
+            t_start=s.t_start, tail_tokens=s.tail_tokens))
         if self.prefix is not None and s.nodes:
             self.prefix.release(s.nodes)
         self.cache = self._evict(self.cache, jnp.asarray(b, jnp.int32))
@@ -598,6 +629,38 @@ class ServeEngine:
 
     # -- main loop --------------------------------------------------------
 
+    def _take_tokens(self, live: list[int], toks: np.ndarray,
+                     now: float) -> None:
+        """Advance every slot that took part in a decode step by the token
+        it produced: the next prompt token for a tail slot, else a served
+        token; retire finished slots."""
+        for b in live:
+            s = self.slots[b]
+            self._live_slot_steps += 1
+            tk = int(toks[b])
+            self._pos[b] += 1
+            if s.phase == "tail":
+                p = int(self._pos[b])
+                if p < len(s.req.prompt):
+                    self._tok[b, 0] = s.req.prompt[p]
+                else:          # tk is the first generated token
+                    s.phase = "decode"
+                    s.tokens = [tk]
+                    s.remaining = s.req.gen_len - 1
+                    s.t_first = now
+                    self._tok[b, 0] = tk
+                    self._ctr[b] = 1
+                    self._insert_prefix(b)
+                    if s.remaining <= 0:
+                        self._retire(b, now)
+            else:
+                s.tokens.append(tk)
+                self._tok[b, 0] = tk
+                self._ctr[b] += 1
+                s.remaining -= 1
+                if s.remaining == 0:
+                    self._retire(b, now)
+
     def run(self, requests: list[Request],
             step_hook: Callable[[int], None] | None = None) -> ServeReport:
         """Serve ``requests`` to completion; returns the run report.
@@ -618,62 +681,51 @@ class ServeEngine:
 
         while len(finished) < len(reqs):
             now = self._now()
-            while pending and pending[0].arrival <= now:
-                queue.append(pending.popleft())
-            n_left = len(pending) + len(queue)
-            admitted = self._try_admit(queue, now, n_left)
-            live = [b for b, s in enumerate(self.slots)
-                    if s is not None and s.phase != "prefill"]
-            if not live and not self._prefill_q:
-                if not admitted and pending:
-                    self._advance_to(pending[0].arrival)
-                continue
+            if all(s is None for s in self.slots):
+                # an empty engine works this iteration only if it admits
+                while pending and pending[0].arrival <= now:
+                    queue.append(pending.popleft())
+                if not self._can_admit(len(queue), len(pending) + len(queue)):
+                    if pending:
+                        with jax.profiler.TraceAnnotation("repro.serve.wait"):
+                            self._advance_to(pending[0].arrival)
+                    continue
 
-            params = self.db.get()
-            done_prefill = self._prefill_tick(params)
-            did_decode = bool(live)
-            if did_decode:
-                self._cow_tick()
-                toks, self.cache = self._decode(
-                    params, self.cache, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), jnp.asarray(self._rid),
-                    jnp.asarray(self._ctr))
-                toks = np.asarray(toks)
-                self.decode_steps += 1
-            if self.scfg.clock == "steps":
-                self._vnow += 1.0
-            now = self._now()
-            if done_prefill is not None:
-                self._activate_slot(done_prefill[0], done_prefill[1], now)
-            if did_decode:
-                for b in live:
-                    s = self.slots[b]
-                    self._live_slot_steps += 1
-                    tk = int(toks[b])
-                    self._pos[b] += 1
-                    if s.phase == "tail":
-                        p = int(self._pos[b])
-                        if p < len(s.req.prompt):
-                            self._tok[b, 0] = s.req.prompt[p]
-                        else:          # tk is the first generated token
-                            s.phase = "decode"
-                            s.tokens = [tk]
-                            s.remaining = s.req.gen_len - 1
-                            s.t_first = now
-                            self._tok[b, 0] = tk
-                            self._ctr[b] = 1
-                            self._insert_prefix(b)
-                            if s.remaining <= 0:
-                                self._retire(b, now)
-                    else:
-                        s.tokens.append(tk)
-                        self._tok[b, 0] = tk
-                        self._ctr[b] += 1
-                        s.remaining -= 1
-                        if s.remaining == 0:
-                            self._retire(b, now)
-                if step_hook is not None:
-                    step_hook(self.decode_steps)
+            with jax.profiler.StepTraceAnnotation(
+                    "repro.serve.tick", step_num=self.decode_steps):
+                with jax.profiler.TraceAnnotation("repro.serve.admit"):
+                    while pending and pending[0].arrival <= now:
+                        queue.append(pending.popleft())
+                    self._try_admit(queue, now, len(pending) + len(queue))
+                live = [b for b, s in enumerate(self.slots)
+                        if s is not None and s.phase != "prefill"]
+                if not live and not self._prefill_q:
+                    continue           # prompt-only requests, done at once
+
+                params = self.db.get()
+                with jax.profiler.TraceAnnotation("repro.serve.prefill"):
+                    done_prefill = self._prefill_tick(params)
+                if live:
+                    with jax.profiler.TraceAnnotation("repro.serve.decode"):
+                        self._cow_tick()
+                        toks, self.cache = self._decode(
+                            params, self.cache, jnp.asarray(self._tok),
+                            jnp.asarray(self._pos), jnp.asarray(self._rid),
+                            jnp.asarray(self._ctr))
+                    with jax.profiler.TraceAnnotation("repro.serve.readback"):
+                        toks = np.asarray(toks)
+                    self.decode_steps += 1
+                if self.scfg.clock == "steps":
+                    self._vnow += 1.0
+                now = self._now()
+                with jax.profiler.TraceAnnotation("repro.serve.update"):
+                    if done_prefill is not None:
+                        self._activate_slot(done_prefill[0], done_prefill[1],
+                                            now)
+                    if live:
+                        self._take_tokens(live, toks, now)
+            if live and step_hook is not None:
+                step_hook(self.decode_steps)
 
         duration = max(self._now(), 1e-9)
         lat = np.array([f.latency for f in finished])
@@ -695,4 +747,5 @@ class ServeEngine:
             prefill_chunks=self.prefill_chunks,
             prefix_hit_rate=(self.prefix.hit_rate if self.prefix else 0.0),
             utilization=util,
-            outputs={f.rid: f.tokens for f in finished})
+            outputs={f.rid: f.tokens for f in finished},
+            requests=finished, t0=self._t0)

@@ -233,3 +233,99 @@ class TestSampling:
             continuous=False, temperature=0.8, top_p=0.9, sample_seed=8,
             **SCFG)).run(reqs)
         assert c.outputs != b.outputs            # seed matters
+
+
+def _req(rid, arrival, prompt_len, gen_len):
+    from repro.serve.workload import Request
+    return Request(rid=rid, arrival=arrival,
+                   prompt=tuple((7 * rid + i) % 97 + 1
+                                for i in range(prompt_len)),
+                   gen_len=gen_len)
+
+
+# A chunked prompt (20 tokens: chunks at 0, 8 and 12 of 8) and a short one
+# (5 tokens, under the chunk) due at once, then a short one after an idle
+# gap.  By hand, under the steps clock: A is planned at tick 0, chunked at
+# ticks 0-2 and takes its first token at 3; B is planned at tick 3 (behind
+# A in the prefill queue), gets no chunk, and feeds its 5 prompt tokens one
+# a tick through the decode steps of ticks 4-8, so its first token comes at
+# 9 = 3 + 1 + 5; it finishes at 10.  The engine then waits for C (12.5),
+# planned at 12.5 and first at 18.5.  Working iterations: ticks 0-9 and
+# 7 for C; decode steps: ticks 3-9 and C's 6.
+TRACE_REQS = [(0, 0.0, 20, 2), (1, 0.0, 5, 2), (2, 12.5, 5, 2)]
+CHUNKED = dict(SCFG, prefill_chunk=8)
+
+
+class TestTracing:
+    def test_prompt_counters_by_hand(self, model):
+        cfg, params = model
+        rep = ServeEngine(cfg, params, ServeConfig(**CHUNKED)).run(
+            [_req(*r) for r in TRACE_REQS])
+        got = {f.rid: (f.t_start, f.t_first, f.tail_tokens)
+               for f in rep.requests}
+        assert got == {0: (0.0, 3.0, 0), 1: (3.0, 9.0, 5),
+                       2: (12.5, 18.5, 5)}
+        assert rep.prefill_chunks == 3 and rep.decode_steps == 13
+        assert sorted(f.rid for f in rep.requests) == [0, 1, 2]
+        assert rep.t0 > 0
+
+    def test_short_prompt_takes_the_tail(self, model):
+        """Under the chunk: no chunk, every prompt token one a tick, and
+        the first token one tick after the last prompt token's."""
+        cfg, params = model
+        rep = ServeEngine(cfg, params, ServeConfig(**CHUNKED)).run(
+            [_req(0, 0.0, 5, 3)])
+        (f,) = rep.requests
+        assert f.tail_tokens == 5 and f.t_first - f.t_start == 1 + 5
+        assert rep.prefill_chunks == 0 and len(f.tokens) == 3
+
+    def test_whole_prompt_starts_at_admission(self, model):
+        """One slot: B waits for A to retire (tick 2), and its prompt work
+        starts at its admission there, not at its arrival."""
+        cfg, params = model
+        rep = ServeEngine(cfg, params, ServeConfig(
+            **dict(SCFG, batch_size=1))).run(
+                [_req(0, 0.0, 6, 3), _req(1, 0.0, 6, 2)])
+        got = {f.rid: (f.arrival, f.t_start, f.t_first, f.tail_tokens)
+               for f in rep.requests}
+        assert got == {0: (0.0, 0.0, 0.0, 0), 1: (0.0, 2.0, 2.0, 0)}
+
+    def test_spans_in_a_profiler_trace(self, model, tmp_path):
+        """One tick span per working iteration; every other engine span
+        inside a tick, but the wait; the step hook outside every tick."""
+        from jax.profiler import ProfileData
+        cfg, params = model
+        eng = ServeEngine(cfg, params, ServeConfig(**CHUNKED))
+        reqs = [_req(*r) for r in TRACE_REQS]
+        eng._warmup(reqs)
+
+        def hook(step):
+            with jax.profiler.TraceAnnotation("test.hook"):
+                pass
+
+        with jax.profiler.trace(str(tmp_path)):
+            rep = eng.run(reqs, step_hook=hook)
+        (pb,) = tmp_path.rglob("*.xplane.pb")
+        ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for p in ProfileData.from_file(str(pb)).planes
+              if p.name.startswith("/host:") for line in p.lines
+              for e in line.events
+              if e.name.startswith(("repro.serve.", "test.hook"))]
+        by = {}
+        for name, a, b in ev:
+            by.setdefault(name, []).append((a, b))
+        ticks = by["repro.serve.tick"]
+        assert len(ticks) == 10 + 7
+        assert len(by["repro.serve.wait"]) == 1
+        assert len(by["repro.serve.admit"]) == len(ticks)
+        assert len(by["repro.serve.readback"]) == rep.decode_steps == 13
+        assert len(by["test.hook"]) == rep.decode_steps
+
+        def inside(a, b):
+            return any(x <= a and b <= y for x, y in ticks)
+
+        for name in ("admit", "prefill", "decode", "readback", "update"):
+            spans = by[f"repro.serve.{name}"]
+            assert spans and all(inside(a, b) for a, b in spans), name
+        for a, b in by["repro.serve.wait"] + by["test.hook"]:
+            assert all(b <= x or a >= y for x, y in ticks)
