@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (VMEM_BUDGET, choose_blocks,
+                                           flash_attention, vmem_bytes)
 from repro.kernels.moe_matmul import moe_grouped_ffn
 from repro.kernels.page_gather import page_gather
 from repro.kernels.rglru_scan import rglru_scan
@@ -42,6 +43,46 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    **TOL[dtype])
+
+    @pytest.mark.parametrize("S,H,KV,causal,window", [
+        (256, 6, 2, True, 0),        # GQA 3:1
+        (256, 6, 2, True, 64),       # GQA 3:1, window narrower than S
+        (256, 6, 2, False, 0),       # GQA 3:1, non-causal
+        (256, 4, 4, True, 0),        # MHA
+        (200, 6, 2, True, 0),        # ragged S (padding path)
+        (1024, 3, 1, True, 0),       # several q and kv blocks: masked steps
+        (1100, 3, 1, True, 300),     # ragged, windowed, several blocks
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_chosen_blocks_match_ref(self, S, H, KV, causal, window, dtype):
+        """Blocks left to the chooser, at hd 64."""
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = _rand(ks[0], (1, S, H, 64), dtype)
+        k = _rand(ks[1], (1, S, KV, 64), dtype)
+        v = _rand(ks[2], (1, S, KV, 64), dtype)
+        want = ref.attention(q, k, v, causal=causal, window=window)
+        got = flash_attention(q, k, v, causal=causal, window=window,
+                              interpret=True)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   **TOL[dtype])
+
+    @pytest.mark.parametrize("S", [33, 1024, 4096])
+    @pytest.mark.parametrize("hd", [64, 128, 256])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("group,causal,window", [
+        (3, True, 0), (1, True, 0), (4, False, 0), (3, True, 256)])
+    def test_block_chooser(self, S, hd, dtype, group, causal, window):
+        """(8, 128)-legal blocks that tile the padded sequence and fit the
+        VMEM budget the chooser assumes."""
+        bq, bk = choose_blocks(S, hd, group, dtype, causal, window)
+        s_pad = -(-S // 128) * 128
+        assert bq % 8 == 0 and bk % 128 == 0
+        assert bq % bk == 0 and s_pad % bq == 0
+        if window:
+            assert bk <= max(128, window)
+        assert vmem_bytes(group, bq, bk, hd, jnp.dtype(dtype).itemsize) \
+            <= VMEM_BUDGET
 
     def test_grad_matches_ref(self):
         """The dispatch's Pallas forward is differentiable (training runs

@@ -57,6 +57,14 @@ def _prefill(B, L, C, dt):
              ((B, C), i32)])
 
 
+def _flash(B, S, nh, nkv, hd, dt, window=0):
+    """At the blocks the wrapper chooses for this shape."""
+    return (lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            window=window),
+            [((B, S, nh, hd), dt), ((B, S, nkv, hd), dt),
+             ((B, S, nkv, hd), dt)])
+
+
 CASES = {
     "decode-bf16-b8-l512": _decode(8, 512, bf16),
     "decode-f32-b4-l512": _decode(4, 512, f32),
@@ -69,10 +77,12 @@ CASES = {
         [((8 * 32 + 1, PAGE, KV, HD), bf16), ((8, 32), i32)]),
     "prefill-bf16-b1-c128": _prefill(1, 512, 128, bf16),
     "prefill-bf16-b2-c128": _prefill(2, 512, 128, bf16),
-    "flash-bf16-b8-s1024": (
-        lambda q, k, v: flash_attention(q, k, v, causal=True),
-        [((8, 1024, H, HD), bf16), ((8, 1024, KV, HD), bf16),
-         ((8, 1024, KV, HD), bf16)]),
+    "flash-bf16-b8-s1024": _flash(8, 1024, H, KV, HD, bf16),
+    "flash-bf16-b2-s1024-window256": _flash(2, 1024, H, KV, HD, bf16,
+                                            window=256),
+    "flash-f32-b8-s1024": _flash(8, 1024, H, KV, HD, f32),
+    "flash-bf16-b2-s2048-hd128": _flash(2, 2048, 32, 8, 128, bf16),
+    "flash-f32-b2-s1024-hd256": _flash(2, 1024, 8, 4, 256, f32),
 }
 
 
