@@ -10,7 +10,35 @@ belongs to it is found by name:
   names ``bench/drivers/<driver>.py``;
 * ``bench/limits/<cell>.json``      the limit of each number ``correct``
   compares, with the readings it was set from;
-* ``bench/metrics/<metric>.py``     one reader per per-layer metric.
+* ``bench/metrics/<metric>.py``     one reader per per-layer metric;
+* ``bench/arch/<model_type>.py``    everything that depends on the
+  architecture, found by the configuration's ``model_type`` (``arch``).
+
+How to add an architecture.  One module, ``bench/arch/<model_type>.py``,
+holds what the shared files dispatch to:
+
+* ``dims(config)``: the shapes and constants the rest of the module reads;
+* ``layout(config)``: ``{path: (shape, kind, std)}`` of every weight, in the
+  program's parameter tree (kind ``normal`` with its std, or ``scale``, a
+  norm's gain); ``empty_groups(config)``: the paths of the groups the
+  program's tree holds with no weights in them (a norm without a gain);
+* ``hidden(w, tokens, m, numerics)`` and ``logits_at(w, h, numerics)``: the
+  plain float32 forward, every matrix product through ``reference.mm`` so
+  that the float8 control covers each one;
+* ``model_config(config)``: the program's ``ModelConfig``, importing the
+  program inside the function, so that the rest of the module imports
+  nothing of it;
+* ``matmul_params(config)``, ``attn_flops(config, kv_positions)``,
+  ``kv_bytes(config, positions)`` and ``attn_call(config, batch, seq)``:
+  the counts the readers take (``bench/counts.py``).
+
+A new model then comes as new files alone: its arch module (where its
+``model_type`` has none yet), its configuration, its traffic, its cell's
+limits and its metric readers, with the cell's name added to the
+``workloads`` lists of the end-to-end metrics it reports in
+``BENCHMARK.json``.  It edits no file that is here already: no shared
+module, driver, reader, arch module, configuration or limits of another
+cell.
 """
 from __future__ import annotations
 
@@ -59,6 +87,7 @@ class Cell:
     config: dict
     traffic: dict
     driver: ModuleType
+    arch: ModuleType
     limits: dict
     end_to_end: list[dict]        # BENCHMARK.json entries this cell reports
     per_layer: list[dict]
@@ -78,8 +107,19 @@ def resolve(name: str, bench: dict | None = None) -> Cell:
            if name in m.get("workloads", [name])]
     per_layer = [m for m in bench["per_layer"] if name in m["workloads"]]
     return Cell(name=name, chips=int(entry["chips"]), config=config,
-                traffic=traffic, driver=driver, limits=limits,
+                traffic=traffic, driver=driver, arch=arch(config),
+                limits=limits,
                 end_to_end=e2e, per_layer=per_layer)
+
+
+def arch(config: dict) -> ModuleType:
+    """The module of the configuration's architecture,
+    ``bench/arch/<model_type>.py``, imported as ``bench.arch.<model_type>``
+    (the name by which one arch module imports another)."""
+    path = BENCH / "arch" / f"{config['model_type']}.py"
+    if not path.is_file():
+        raise BenchError(f"missing benchmark file {path.relative_to(ROOT)}")
+    return importlib.import_module(f"bench.arch.{path.stem}")
 
 
 def metric_reader(name: str) -> ModuleType:

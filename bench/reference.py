@@ -1,4 +1,4 @@
-"""The plain reference of a dense decoder, and the control beside it.
+"""The plain reference of a decoder, and the control beside it.
 
 Written from the configuration file alone: it imports nothing of the
 program and reads only the weights that ``bench/weights.py`` makes from the
@@ -8,10 +8,9 @@ computation with the operands of every matrix product rounded to float8
 (e4m3 forward, e5m2 for the cotangents in the backward pass), each tensor
 scaled by its own largest magnitude first, as fp8 training and serving do.
 
-The forward is a Llama-style decoder: pre-norm blocks, rotary positions on
-the two halves of each head, grouped-query attention with head ``h`` reading
-key/value head ``h // (H / KV)``, a SwiGLU MLP and a head tied to the
-embedding.
+The forward itself, ``hidden`` and ``logits_at``, is the architecture's
+(``bench/arch/<model_type>.py``), every product through ``mm``; the loss,
+the training steps and the served logits here are the same for all.
 """
 from __future__ import annotations
 
@@ -21,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench import harness, weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8, F8_GRAD = jnp.float8_e4m3fn, jnp.float8_e5m2
@@ -61,66 +60,12 @@ def mm(eq: str, a, b, numerics: str):
                       preferred_element_type=jnp.float32)
 
 
-def _norm(x, scale, m):
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + m["eps"])
-    return x * scale
-
-
-def _rope(x, pos, theta):
-    hd = x.shape[-1]
-    freqs = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd)
-    ang = pos[:, None].astype(jnp.float32) * freqs          # (S, hd/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _block(x, lw, m, numerics):
-    B, S, _ = x.shape
-    H, KV, hd = m["H"], m["KV"], m["hd"]
-    pos = jnp.arange(S)
-    h = _norm(x, lw["ln1"], m)
-    q = mm("bsd,de->bse", h, lw["wq"], numerics).reshape(B, S, H, hd)
-    k = mm("bsd,de->bse", h, lw["wk"], numerics).reshape(B, S, KV, hd)
-    v = mm("bsd,de->bse", h, lw["wv"], numerics).reshape(B, S, KV, hd)
-    q, k = _rope(q, pos, m["theta"]), _rope(k, pos, m["theta"])
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    s = mm("bqhd,bkhd->bhqk", q, k, numerics) * hd ** -0.5
-    s = jnp.where(pos[None, :] <= pos[:, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = mm("bhqk,bkhd->bqhd", p, v, numerics).reshape(B, S, H * hd)
-    x = x + mm("bse,ed->bsd", o, lw["wo"], numerics)
-    h = _norm(x, lw["ln2"], m)
-    g = jax.nn.silu(mm("bsd,df->bsf", h, lw["wg"], numerics))
-    u = mm("bsd,df->bsf", h, lw["wu"], numerics)
-    return x + mm("bsf,fd->bsd", g * u, lw["wd"], numerics)
-
-
-def hidden(w: dict, tokens, m: dict, numerics: str):
-    """Final-normed hidden states (B, S, d) of ``tokens`` (B, S)."""
-    blk = w["groups"]["g0"]["s0"]
-    per_layer = {"wq": blk["mix"]["wq"], "wk": blk["mix"]["wk"],
-                 "wv": blk["mix"]["wv"], "wo": blk["mix"]["wo"],
-                 "wg": blk["ffn"]["wg"], "wu": blk["ffn"]["wu"],
-                 "wd": blk["ffn"]["wd"], "ln1": blk["ln1"]["scale"],
-                 "ln2": blk["ln2"]["scale"]}
-    x = w["embedding"][tokens].astype(jnp.float32)
-    body = jax.checkpoint(lambda x, lw: (_block(x, lw, m, numerics), None))
-    x, _ = jax.lax.scan(body, x, per_layer)
-    return _norm(x, w["final_norm"]["scale"], m)
-
-
-def logits_at(w: dict, h, numerics: str):
-    """Logits (..., V) of hidden states (..., d) through the tied head."""
-    return mm("...d,vd->...v", h, w["embedding"], numerics)
-
-
-def loss(w: dict, tokens, labels, m: dict, numerics: str,
+def loss(arch, w: dict, tokens, labels, m: dict, numerics: str,
          chunk: int = 128):
     """Mean next-token cross entropy over every position, the logits made
-    ``chunk`` positions at a time so that (B, S, V) never exists."""
-    h = hidden(w, tokens, m, numerics)
+    ``chunk`` positions at a time so that (B, S, V) never exists;
+    ``arch`` is the architecture's module, ``m`` its ``dims``."""
+    h = arch.hidden(w, tokens, m, numerics)
     B, S, d = h.shape
     n = S // chunk
     hs = jnp.moveaxis(h.reshape(B, n, chunk, d), 1, 0)
@@ -128,7 +73,7 @@ def loss(w: dict, tokens, labels, m: dict, numerics: str,
 
     @jax.checkpoint
     def part(hc, lc):
-        lg = logits_at(w, hc, numerics)
+        lg = arch.logits_at(w, hc, numerics)
         lse = jax.scipy.special.logsumexp(lg, -1)
         pick = jnp.take_along_axis(lg, lc[..., None], -1)[..., 0]
         return jnp.sum(lse - pick)
@@ -152,12 +97,14 @@ def _global_clip(g: dict, max_norm: float) -> dict:
 @functools.lru_cache(maxsize=8)
 def _train_fns(config_json: str, numerics: str, opt_json: str):
     import json
-    m = W.dims(json.loads(config_json))
+    config = json.loads(config_json)
+    arch, m = harness.arch(config), W.dims(config)
     o = json.loads(opt_json)
 
     @jax.jit
     def grad(w, tokens, labels):
-        return jax.value_and_grad(loss)(w, tokens, labels, m, numerics)
+        return jax.value_and_grad(loss, argnums=1)(arch, w, tokens, labels,
+                                                   m, numerics)
 
     # the gradient and the moments are dead after the update: donated, so
     # that the versions the ring keeps fit beside them at the cell's size
@@ -243,12 +190,13 @@ BUCKET = 256
 @functools.lru_cache(maxsize=8)
 def _serve_fn(config_json: str, numerics: str):
     import json
-    m = W.dims(json.loads(config_json))
+    config = json.loads(config_json)
+    arch, m = harness.arch(config), W.dims(config)
 
     @jax.jit
     def fn(w, tokens, positions):
-        h = hidden(w, tokens[None], m, numerics)[0]
-        return logits_at(w, h[positions], numerics)
+        h = arch.hidden(w, tokens[None], m, numerics)[0]
+        return arch.logits_at(w, h[positions], numerics)
     return fn
 
 

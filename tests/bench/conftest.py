@@ -32,10 +32,31 @@ SMOKE_TRAFFIC = {
 SMOKE_LR = 1e-3
 
 
-def smoke(cell):
-    """The cell at smoke widths, with the Pallas kernels interpreted."""
+# OLMo-1B as allenai/OLMo-1B-hf's config.json states it, at smoke widths;
+# the norm's epsilon is the one the program's norms add (the published
+# LayerNorm adds 1e-5, which the olmo module takes where the file is silent)
+OLMO = {"model_type": "olmo", "arch": "olmo-1b", "hidden_act": "silu",
+        "rope_theta": 10000.0, "tie_word_embeddings": True,
+        "max_position_embeddings": 2048, "compute_dtype": "bfloat16",
+        "param_dtype": "float32", "layer_norm_eps": 1e-6,
+        **SMOKE_MODEL, "num_key_value_heads": 4}
+del OLMO["head_dim"]                 # OLMo states none: d / H
+# the configurations a cell is rehearsed with, from the cell's own
+SMOKE_MODELS = {
+    "llama": lambda config: {**config, **SMOKE_MODEL},
+    "llama_untied": lambda config: {**config, **SMOKE_MODEL,
+                                    "tie_word_embeddings": False},
+    "olmo": lambda config: dict(OLMO),
+}
+
+
+def smoke(cell, model: str = "llama"):
+    """The cell at smoke widths, with the Pallas kernels interpreted, its
+    configuration one of ``SMOKE_MODELS``."""
+    from bench import harness
     cell = copy.copy(cell)
-    cell.config = {**cell.config, **SMOKE_MODEL}
+    cell.config = SMOKE_MODELS[model](cell.config)
+    cell.arch = harness.arch(cell.config)
     cell.traffic = {**cell.traffic, **SMOKE_TRAFFIC[cell.traffic["kind"]],
                     "kernels": "interpret"}
     if "optimizer" in cell.traffic:
@@ -48,8 +69,8 @@ def smoke(cell):
 def smoke_cell(monkeypatch):
     from bench import harness
 
-    def make(name):
-        cell = smoke(harness.resolve(name))
+    def make(name, model="llama"):
+        cell = smoke(harness.resolve(name), model)
         monkeypatch.setenv("REPRO_KERNEL_IMPL", cell.traffic["kernels"])
         return cell
     return make

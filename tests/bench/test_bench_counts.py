@@ -65,8 +65,7 @@ def test_serve_mfu_reader_over_ticks():
 
 
 def test_kernel_counts():
-    flash = harness.metric_reader("flash_attention_roofline")
-    flops, bytes_ = flash.call_flops_bytes(CONFIG, batch=4, seq=128)
+    flops, bytes_ = counts.attn_call(CONFIG, batch=4, seq=128)
     assert flops == 4 * 4 * 4 * 16 * 128 * 128 / 2 == 8_388_608
     assert bytes_ == 4 * 128 * 16 * (2 * 4 + 2 * 2) * 2 == 196_608
     ring = harness.metric_reader("ring_gather_roofline")
@@ -88,7 +87,7 @@ def test_roofline_reader_on_trace():
            "devices": ["d"], "config": CONFIG, "peaks": PEAKS,
            "traffic": {"batch": 4, "seq": 128}}
     flash = harness.metric_reader("flash_attention_roofline")
-    least = counts.roofline_s(*flash.call_flops_bytes(CONFIG, 4, 128), PEAKS)
+    least = counts.roofline_s(*counts.attn_call(CONFIG, 4, 128), PEAKS)
     assert flash.read(ctx) == pytest.approx(100 * 2 * least / 8e-4)
     assert harness.metric_reader("ring_gather_roofline").read(ctx) is None
 

@@ -26,8 +26,7 @@ def _below_control(res, control):
     assert all(got[k] < control[k] for k in got), (got, control)
 
 
-def test_train_cell_rehearsal(smoke_cell):
-    cell = smoke_cell(TRAIN)
+def _train_rehearsal(cell):
     res = _execute(cell)
     _below_control(res, calibrate.train_control(cell, SEED))
     assert set(res["metrics"]) == {"train_tok_s", "setup_s"}
@@ -35,10 +34,10 @@ def test_train_cell_rehearsal(smoke_cell):
     assert res["notes"]["compiles_in_window"] == 0
     assert list(res)[-1] == "checks"
     assert set(res["checks"]) == {"loss_gap", "grad_gap", "change_gap"}
+    return res
 
 
-def test_serve_cell_rehearsal(smoke_cell):
-    cell = smoke_cell(SERVE)
+def _serve_rehearsal(cell):
     res = _execute(cell)
     served = calibrate.serve_sample(cell, SEED, 0.5, jax.devices()[:1])
     _below_control(res, calibrate.serve_control(cell, SEED, served))
@@ -48,6 +47,34 @@ def test_serve_cell_rehearsal(smoke_cell):
     assert res["attempted"] == round(cell.traffic["rate"] * 0.5)
     assert res["notes"]["compiles_in_window"] == 0
     assert res["notes"]["compared_tokens"] > 0
+    return res
+
+
+def test_train_cell_rehearsal(smoke_cell):
+    res = _train_rehearsal(smoke_cell(TRAIN))
+    assert res["correct"], res["checks"]
+
+
+def test_serve_cell_rehearsal(smoke_cell):
+    res = _serve_rehearsal(smoke_cell(SERVE))
+    assert res["correct"], res["checks"]
+
+
+REHEARSAL = {TRAIN: _train_rehearsal, SERVE: _serve_rehearsal}
+
+
+@pytest.mark.parametrize("name", [TRAIN, SERVE])
+def test_olmo_rehearsal(smoke_cell, name):
+    res = REHEARSAL[name](smoke_cell(name, "olmo"))
+    assert res["correct"], res["checks"]
+
+
+# held to the control alone: the limits are the cell's, set for its tied
+# head, whose logits are smaller than a head of its own gives (the
+# embedding's std 0.02 against the head's d ** -0.5)
+@pytest.mark.parametrize("name", [TRAIN, SERVE])
+def test_untied_head_rehearsal(smoke_cell, name):
+    REHEARSAL[name](smoke_cell(name, "llama_untied"))
 
 
 # ---------------------------------------------------------------------------
@@ -119,6 +146,16 @@ def test_serve_fault_is_not_correct(smoke_cell, monkeypatch, fault):
     _broken_serve(monkeypatch, cell, fault)
     res = _execute(cell)
     assert not res["correct"], res["checks"]
+
+
+def test_olmo_faults_are_not_correct(smoke_cell, monkeypatch):
+    train = smoke_cell(TRAIN, "olmo")
+    _broken_train(monkeypatch, train, "half_batch")
+    serve = smoke_cell(SERVE, "olmo")
+    _broken_serve(monkeypatch, serve, "token_altered")
+    for cell in (train, serve):
+        res = _execute(cell)
+        assert not res["correct"], res["checks"]
 
 
 # ---------------------------------------------------------------------------
